@@ -75,6 +75,7 @@ from repro.core import CompressionPolicy, compress_tree, spectralize_params
 from repro.data.synthetic import SyntheticLM
 from repro.models.model import build_model
 from repro.runtime import dispatch
+from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.dispatch import BACKENDS, DispatchConfig, use_dispatch
 
 
@@ -1781,6 +1782,7 @@ if __name__ == "__main__":
                     help="allowed relative drop vs the baseline (CI runners "
                     "are noisy; this gates collapses, not drift)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.trace == "poisson":
         common = dict(
             rate=args.rate,

@@ -32,44 +32,11 @@ import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
-
-try:  # optional dep: fall back to stdlib zlib where zstandard is absent
-    import zstandard
-except ImportError:  # pragma: no cover
-    zstandard = None
+import zstandard
 
 __all__ = ["Checkpointer", "latest_step", "save", "restore"]
 
 _FORMAT_VERSION = 2
-
-
-class _Codec:
-    """zstd when available, zlib otherwise; recorded in the manifest so a
-    checkpoint restores correctly regardless of which env wrote it."""
-
-    @staticmethod
-    def default() -> str:
-        return "zstd" if zstandard is not None else "zlib"
-
-    @staticmethod
-    def compress(raw: bytes, codec: str) -> bytes:
-        if codec == "zstd":
-            if zstandard is None:
-                raise ImportError("checkpoint written with zstd but zstandard not installed")
-            return zstandard.ZstdCompressor(level=3).compress(raw)
-        import zlib
-
-        return zlib.compress(raw, 3)
-
-    @staticmethod
-    def decompress(blob: bytes, codec: str) -> bytes:
-        if codec == "zstd":
-            if zstandard is None:
-                raise ImportError("checkpoint written with zstd but zstandard not installed")
-            return zstandard.ZstdDecompressor().decompress(blob)
-        import zlib
-
-        return zlib.decompress(blob)
 
 
 def _leaf_files(flat):
@@ -94,23 +61,19 @@ def _write(host_leaves, paths, directory, step, extra):
     if os.path.exists(tmp):
         shutil.rmtree(tmp)
     os.makedirs(tmp)
-    codec = _Codec.default()
     manifest = {
-        # zstd manifests stay at version 2 (readable by pre-codec readers);
-        # zlib leaves are NOT, so the version bump makes the incompatibility
-        # explicit instead of an opaque zstd frame error downstream.
-        "version": _FORMAT_VERSION if codec == "zstd" else _FORMAT_VERSION + 1,
+        "version": _FORMAT_VERSION,
         "step": step,
         "extra": extra,
-        "codec": codec,
         "leaves": [],
     }
+    zc = zstandard.ZstdCompressor(level=3)
     for i, (arr, path) in enumerate(zip(host_leaves, paths)):
         fname = f"leaf_{i:05d}.npy.zst"
         raw = arr.tobytes()
         digest = hashlib.sha256(raw).hexdigest()[:16]
         with open(os.path.join(tmp, fname), "wb") as f:
-            f.write(_Codec.compress(raw, codec))
+            f.write(zc.compress(raw))
         manifest["leaves"].append(
             {
                 "path": path,
@@ -163,10 +126,10 @@ def restore(
     with open(os.path.join(d, "manifest.msgpack"), "rb") as f:
         manifest = msgpack.unpackb(f.read())
     version = manifest.get("version", 1)
-    if version not in (1, 2, 3):
+    if version not in (1, 2):
         raise ValueError(
             f"checkpoint format version {version} not supported by this reader "
-            f"(known: 1-3)"
+            f"(known: 1-2)"
         )
     flat, treedef = jax.tree_util.tree_flatten_with_path(template)
     if len(flat) != len(manifest["leaves"]):
@@ -174,7 +137,7 @@ def restore(
             f"checkpoint has {len(manifest['leaves'])} leaves, template has {len(flat)}"
         )
     by_path = {m["path"]: m for m in manifest["leaves"]}
-    codec = manifest.get("codec", "zstd")  # pre-codec checkpoints were zstd
+    zd = zstandard.ZstdDecompressor()
     sh_flat = (
         treedef.flatten_up_to(shardings) if shardings is not None else [None] * len(flat)
     )
@@ -182,7 +145,7 @@ def restore(
     for (path, leaf), sh in zip(flat, sh_flat):
         meta = by_path[_path_str(path)]
         with open(os.path.join(d, meta["file"]), "rb") as f:
-            raw = _Codec.decompress(f.read(), codec)
+            raw = zd.decompress(f.read())
         if validate and hashlib.sha256(raw).hexdigest()[:16] != meta["sha"]:
             raise IOError(f"checksum mismatch for {meta['path']}")
         arr = np.frombuffer(raw, dtype=np.dtype(meta["dtype"])).reshape(meta["shape"])

@@ -101,9 +101,7 @@ def distributed_rsi(
     body = functools.partial(
         _rsi_block, k=k, q=q, row_axis=row_axis, col_axis=col_axis
     )
-    from repro.runtime.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(row_axis, col_axis), P(col_axis, None)),

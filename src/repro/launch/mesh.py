@@ -14,9 +14,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     pipeline stages via repro.train.pipeline_parallel)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    from repro.runtime.compat import make_mesh
-
-    return make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_rules(mesh, *, sequence_parallel: bool = True):

@@ -156,8 +156,10 @@ def main(argv=None):
     from repro.data.synthetic import SyntheticLM
     from repro.models.model import build_model
     from repro.runtime import dispatch
+    from repro.runtime.compile_cache import use_compile_cache
     from repro.runtime.dispatch import DispatchConfig, use_dispatch
 
+    use_compile_cache()
     cfg = get_arch(args.arch, reduced=args.reduced)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
@@ -177,6 +179,9 @@ def main(argv=None):
         if args.kernels is not None
         else DispatchConfig.from_arch(cfg)
     )
+    dev = jax.devices()[0]
+    print(f"[device] {dev.platform} {dev.device_kind} x{len(jax.devices())}, "
+          f"Pallas interpret mode {dcfg.interpret_mode()}")
     dispatch.reset_counters()
 
     if args.engine == "static":

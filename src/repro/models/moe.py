@@ -202,9 +202,7 @@ def _moe_expert_parallel(p, x, cfg, rules):
         out = jax.lax.psum(out, "model")
         return out.astype(x_blk.dtype).reshape(Bl, Sl, d), aux
 
-    from repro.runtime.compat import shard_map
-
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(P(), e_spec, x_spec),

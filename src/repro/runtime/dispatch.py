@@ -150,6 +150,13 @@ class DispatchConfig:
     def replace(self, **kw) -> "DispatchConfig":
         return dataclasses.replace(self, **kw)
 
+    def interpret_mode(self, platform: Optional[str] = None) -> bool:
+        """Whether Pallas kernels run interpreted: the pinned ``interpret``,
+        else True off a TPU (only a TPU lowers Pallas-TPU natively)."""
+        if self.interpret is not None:
+            return self.interpret
+        return _platform(platform) != "tpu"
+
 
 _state = threading.local()
 _DEFAULT = DispatchConfig()
@@ -222,12 +229,6 @@ def format_counters() -> str:
 # --------------------------------------------------------------------------- #
 def _platform(platform: Optional[str]) -> str:
     return platform if platform is not None else jax.default_backend()
-
-
-def _interpret(config: DispatchConfig, platform: str) -> bool:
-    if config.interpret is not None:
-        return config.interpret
-    return platform != "tpu"
 
 
 def _break_even_rank(d_in: int, d_out: int) -> int:
@@ -336,14 +337,14 @@ def lowrank_apply(x: jax.Array, A: jax.Array, B: jax.Array) -> jax.Array:
     if path == PATH_FUSED:
         y = lowrank_matmul_pallas(
             xc.reshape(-1, K), Ac, Bc,
-            interpret=_interpret(config, platform),
+            interpret=config.interpret_mode(platform),
             vmem_limit=config.vmem_limit_bytes,
         )
         return y.reshape(out_shape)
     if path == PATH_FUSED_BATCHED:
         y = lowrank_matmul_batched_pallas(
             xc, Ac, Bc,
-            interpret=_interpret(config, platform),
+            interpret=config.interpret_mode(platform),
             vmem_limit=config.vmem_limit_bytes,
         )
         return y.reshape(out_shape)
@@ -366,7 +367,7 @@ def sketch_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
     platform = _platform(None)
     if _use_pallas("sketch_matmul", config, platform):
         _record("sketch_matmul", "pallas", (a.shape, b.shape))
-        return sketch_matmul_pallas(a, b, interpret=_interpret(config, platform))
+        return sketch_matmul_pallas(a, b, interpret=config.interpret_mode(platform))
     _record("sketch_matmul", "xla", (a.shape, b.shape))
     return _ref.sketch_matmul_ref(a, b)
 
@@ -378,7 +379,7 @@ def ssd_scan(x, dt, B_in, C_in, A, *, chunk: int = 128):
     if _use_pallas("ssd_scan", config, platform):
         _record("ssd_scan", "pallas", (x.shape, chunk))
         return ssd_scan_pallas(
-            x, dt, B_in, C_in, A, chunk=chunk, interpret=_interpret(config, platform)
+            x, dt, B_in, C_in, A, chunk=chunk, interpret=config.interpret_mode(platform)
         )
     _record("ssd_scan", "xla", (x.shape, chunk))
     xbar = (x.astype(jnp.float32) * dt[..., None].astype(jnp.float32)).astype(x.dtype)
@@ -392,7 +393,7 @@ def flash_attention(q, k, v, *, causal: bool = True):
     if _use_pallas("flash_attention", config, platform):
         _record("flash_attention", "pallas", (q.shape, causal))
         return flash_attention_pallas(
-            q, k, v, causal=causal, interpret=_interpret(config, platform)
+            q, k, v, causal=causal, interpret=config.interpret_mode(platform)
         )
     _record("flash_attention", "xla", (q.shape, causal))
     return _ref.flash_attention_ref(q, k, v, causal=causal)
@@ -421,7 +422,10 @@ def choose_decode_path(
         return "pallas"
     if be in ("xla", "reference"):
         return "xla"
-    if platform == "tpu" and kv_shape[1] >= DECODE_MIN_SEQ:
+    S = kv_shape[1]
+    # the kernel's mask block puts the sequence block on the lane dim, which
+    # Mosaic tiles by 128: misaligned depths stay on the einsum
+    if platform == "tpu" and S >= DECODE_MIN_SEQ and S % 128 == 0:
         return "pallas"
     return "xla"
 
@@ -441,7 +445,7 @@ def decode_attention(q, k_cache, v_cache, valid):
     _record("decode_attention", path, (B, S, KV, H // KV, hd))
     if path == "pallas":
         return decode_attention_pallas(
-            q, k_cache, v_cache, valid, interpret=_interpret(config, platform)
+            q, k_cache, v_cache, valid, interpret=config.interpret_mode(platform)
         )
     return _ref.decode_attention_ref(q, k_cache, v_cache, valid)
 
@@ -498,6 +502,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, n_valid):
     if path == "pallas":
         return paged_decode_attention_pallas(
             q, k_pool, v_pool, block_table, n_valid,
-            interpret=_interpret(config, platform),
+            interpret=config.interpret_mode(platform),
         )
     return _ref.paged_decode_attention_ref(q, k_pool, v_pool, block_table, n_valid)

@@ -37,9 +37,7 @@ def _stage_body(block_fn, stage_params, x):
 def pipeline_forward(block_fn, params_stack, x_mb, *, axis: str = "pod"):
     """shard_map body: params_stack (L/S, ...) local slice; x_mb (M, b, ...)
     microbatches (replicated input).  Returns (M, b, ...) outputs."""
-    from repro.runtime.compat import axis_size
-
-    S = axis_size(axis)
+    S = jax.lax.axis_size(axis)
     stage = jax.lax.axis_index(axis)
     M = x_mb.shape[0]
     T = M + S - 1  # total pipeline ticks
@@ -86,9 +84,7 @@ def gpipe_apply(block_fn, mesh, *, n_microbatches: int, axis: str = "pod"):
 
         body = functools.partial(pipeline_forward, block_fn, axis=axis)
         param_spec = jax.tree_util.tree_map(lambda _: P(axis), params_stack)
-        from repro.runtime.compat import shard_map
-
-        out = shard_map(
+        out = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(param_spec, P()),

@@ -3,9 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (

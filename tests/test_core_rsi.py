@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     CompressionPolicy,
@@ -146,10 +147,8 @@ def test_compress_tree_end_to_end_quality():
 
 
 # --------------------------------------------------------------------------- #
-# property tests: Alg 3.1 error monotonicity + factored-form consistency.
-# hypothesis-driven where the optional dep is installed (importorskip idiom,
-# cf. test_bounds); a deterministic seed sweep keeps the properties covered
-# in minimal environments.
+# property tests: Alg 3.1 error monotonicity + factored-form consistency,
+# as a fixed seed sweep and as a hypothesis sweep.
 # --------------------------------------------------------------------------- #
 def _rsi_fro_err(W, k, q, key, **kw):
     res = rsi(W, k, q, key, **kw)
@@ -188,42 +187,34 @@ def test_rsi_error_monotonicity_properties(seed):
     _check_q_and_oversample_monotone(seed)
 
 
-try:  # hypothesis property sweep where the optional dep is installed
-    from hypothesis import given, settings, strategies as st
+@settings(max_examples=8, deadline=None)
+@given(seed=st.integers(0, 2**31 - 1))
+def test_rsi_error_monotonicity_property_sweep(seed):
+    _check_q_and_oversample_monotone(seed)
 
-    HAVE_HYPOTHESIS = True
-except ImportError:  # pragma: no cover
-    HAVE_HYPOTHESIS = False
 
-if HAVE_HYPOTHESIS:
-
-    @settings(max_examples=8, deadline=None)
-    @given(seed=st.integers(0, 2**31 - 1))
-    def test_rsi_error_monotonicity_property_sweep(seed):
-        _check_q_and_oversample_monotone(seed)
-
-    @settings(max_examples=8, deadline=None)
-    @given(
-        seed=st.integers(0, 2**31 - 1),
-        k=st.integers(4, 32),
-        q=st.integers(1, 4),
+@settings(max_examples=8, deadline=None)
+@given(
+    seed=st.integers(0, 2**31 - 1),
+    k=st.integers(4, 32),
+    q=st.integers(1, 4),
+)
+def test_rsi_factors_reconstruction_property(seed, k, q):
+    """For arbitrary (seed, rank, q): the paper's factored form A @ B
+    matches the full U diag(S) V^T reconstruction to tolerance."""
+    C, D = 64, 96
+    W = synth_spectrum_matrix(
+        jax.random.PRNGKey(seed), C, D, vgg_like_spectrum(C)
     )
-    def test_rsi_factors_reconstruction_property(seed, k, q):
-        """For arbitrary (seed, rank, q): the paper's factored form A @ B
-        matches the full U diag(S) V^T reconstruction to tolerance."""
-        C, D = 64, 96
-        W = synth_spectrum_matrix(
-            jax.random.PRNGKey(seed), C, D, vgg_like_spectrum(C)
-        )
-        A, B = rsi_factors(W, k, q, jax.random.PRNGKey(seed + 1))
-        res = rsi(W, k, q, jax.random.PRNGKey(seed + 1))
-        assert A.shape == (C, k) and B.shape == (k, D)
-        np.testing.assert_allclose(
-            np.asarray(A @ B),
-            np.asarray((res.U * res.S[None, :]) @ res.Vt),
-            rtol=2e-4,
-            atol=2e-4,
-        )
+    A, B = rsi_factors(W, k, q, jax.random.PRNGKey(seed + 1))
+    res = rsi(W, k, q, jax.random.PRNGKey(seed + 1))
+    assert A.shape == (C, k) and B.shape == (k, D)
+    np.testing.assert_allclose(
+        np.asarray(A @ B),
+        np.asarray((res.U * res.S[None, :]) @ res.Vt),
+        rtol=2e-4,
+        atol=2e-4,
+    )
 
 
 def test_compress_tree_energy_rule():

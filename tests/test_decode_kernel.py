@@ -133,6 +133,9 @@ def test_choose_decode_path_auto_table():
     assert dispatch.choose_decode_path(q_shape, kv_shallow, config=cfg, platform="tpu") == "xla"
     assert dispatch.choose_decode_path(q_shape, kv_deep, config=cfg, platform="cpu") == "xla"
     assert kv_shallow[1] < DECODE_MIN_SEQ <= kv_deep[1]
+    # a depth that is not a multiple of 128 cannot be tiled on the chip
+    kv_misaligned = (4, 1000, 2, 64)
+    assert dispatch.choose_decode_path(q_shape, kv_misaligned, config=cfg, platform="tpu") == "xla"
     # pins override the table everywhere
     pinned = DispatchConfig(backend="pallas")
     assert dispatch.choose_decode_path(q_shape, kv_shallow, config=pinned, platform="cpu") == "pallas"

@@ -1,29 +1,27 @@
 """Multi-device tests (8 fake CPU devices via subprocess: XLA device count
 must be set before jax initializes, so these run in child processes)."""
 
+import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 
 
-def _run(code: str, devices: int = 8):
-    env = {
-        "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
-        "PYTHONPATH": "src",
-        "PATH": "/usr/bin:/bin",
-        "HOME": "/root",
-    }
-    import os
+ROOT = Path(__file__).resolve().parents[1]
 
-    env.update({k: v for k, v in os.environ.items() if k not in env})
+
+def _run(code: str, devices: int = 8):
+    env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["PYTHONPATH"] = str(ROOT / "src")
     r = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True,
         text=True,
-        cwd="/root/repo",
+        cwd=ROOT,
         env=env,
         timeout=900,
     )
@@ -37,8 +35,8 @@ def test_distributed_rsi_matches_single_device():
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.distributed_rsi import distributed_rsi
         from repro.core import rsi, synth_spectrum_matrix, vgg_like_spectrum
-        from repro.runtime.compat import make_mesh
-        mesh = make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         W = synth_spectrum_matrix(jax.random.PRNGKey(0), 256, 512, vgg_like_spectrum(256))
         Wsh = jax.device_put(W, NamedSharding(mesh, P("data", "model")))
         d = distributed_rsi(Wsh, 32, 3, jax.random.PRNGKey(1), mesh)
@@ -47,7 +45,6 @@ def test_distributed_rsi_matches_single_device():
         as_ = (s.U * s.S[None]) @ s.Vt
         err = float(jnp.linalg.norm(ad - as_) / jnp.linalg.norm(as_))
         assert err < 1e-4, err
-        # older jax normalizes away trailing Nones in PartitionSpec
         assert d.U.sharding.spec in (P("data", None), P("data")), d.U.sharding
         assert d.Vt.sharding.spec == P(None, "model"), d.Vt.sharding
         print("OK", err)
@@ -67,8 +64,8 @@ def test_moe_expert_parallel_matches_local():
         p = moe.moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model), jnp.float32)
         ref, aux_ref = moe._moe_local(p, x, cfg)
-        from repro.runtime.compat import make_mesh
-        mesh = make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         rules = MeshRules(mesh)
         with use_rules(rules):
             got, aux = jax.jit(lambda p, x: moe.moe_forward(p, x, cfg))(p, x)
@@ -85,8 +82,8 @@ def test_pipeline_parallel_matches_sequential():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.train.pipeline_parallel import gpipe_apply
-        from repro.runtime.compat import make_mesh
-        mesh = make_mesh((4,), ("pod",))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
         L, d = 8, 16
         ws = jax.random.normal(jax.random.PRNGKey(0), (L, d, d)) / d**0.5
         def block(w, x):
@@ -110,9 +107,9 @@ def test_elastic_checkpoint_reshard():
         import tempfile, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.checkpoint import checkpointer as ckpt
-        from repro.runtime.compat import make_mesh
-        m1 = make_mesh((2, 4), ("data", "model"))
-        m2 = make_mesh((8, 1), ("data", "model"))
+        from jax.sharding import AxisType
+        m1 = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+        m2 = jax.make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         W = jnp.arange(64*32, dtype=jnp.float32).reshape(64, 32)
         state = {"w": jax.device_put(W, NamedSharding(m1, P("data", "model")))}
         with tempfile.TemporaryDirectory() as d:
@@ -132,8 +129,8 @@ def test_powersgd_compressed_allreduce():
         import jax, jax.numpy as jnp, numpy as np, functools
         from repro.core.gradient_compression import (
             PowerSGDConfig, init_powersgd, compress_allreduce, comm_bytes)
-        from repro.runtime.compat import make_mesh
-        mesh = make_mesh((8,), ("data",))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         cfg = PowerSGDConfig(rank=8, min_size=1024)
         # shared low-rank signal + small per-device noise: a rank-8 sketch of
         # the MEAN must capture the signal (pure-noise means are full-rank and
@@ -146,12 +143,11 @@ def test_powersgd_compressed_allreduce():
         def body(g, st):
             out, st2 = compress_allreduce({"w": g}, st, "data", cfg)
             return out["w"], None
-        from repro.runtime.compat import shard_map
-        f = shard_map(lambda g: body(g[0], state)[0][None],
-                      mesh=mesh,
-                      in_specs=jax.sharding.PartitionSpec("data"),
-                      out_specs=jax.sharding.PartitionSpec("data"),
-                      check_vma=False)
+        f = jax.shard_map(lambda g: body(g[0], state)[0][None],
+                          mesh=mesh,
+                          in_specs=jax.sharding.PartitionSpec("data"),
+                          out_specs=jax.sharding.PartitionSpec("data"),
+                          check_vma=False)
         got = f(grads_per_dev)
         dense_mean = jnp.mean(grads_per_dev, axis=0)
         # error feedback handles the residual over steps; single step should

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.lowrank_matmul import lowrank_matmul_pallas
@@ -99,34 +100,25 @@ def test_flash_attention_allclose(B, S, H, hd, causal, dtype):
     )
 
 
-try:  # property tests only where the optional dep is installed
-    from hypothesis import given, settings, strategies as st
-
-    HAVE_HYPOTHESIS = True
-except ImportError:  # pragma: no cover
-    HAVE_HYPOTHESIS = False
-
-if HAVE_HYPOTHESIS:
-
-    @settings(max_examples=15, deadline=None)
-    @given(
-        seed=st.integers(0, 2**31 - 1),
-        M=st.integers(8, 80),
-        K=st.integers(8, 120),
-        r=st.integers(1, 16),
-        N=st.integers(8, 64),
+@settings(max_examples=15, deadline=None)
+@given(
+    seed=st.integers(0, 2**31 - 1),
+    M=st.integers(8, 80),
+    K=st.integers(8, 120),
+    r=st.integers(1, 16),
+    N=st.integers(8, 64),
+)
+def test_lowrank_matmul_property(seed, M, K, r, N):
+    """Property: fused kernel == two exact matmuls for arbitrary shapes."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x, A, B = (
+        jax.random.normal(ks[0], (M, K)),
+        jax.random.normal(ks[1], (K, r)),
+        jax.random.normal(ks[2], (r, N)),
     )
-    def test_lowrank_matmul_property(seed, M, K, r, N):
-        """Property: fused kernel == two exact matmuls for arbitrary shapes."""
-        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-        x, A, B = (
-            jax.random.normal(ks[0], (M, K)),
-            jax.random.normal(ks[1], (K, r)),
-            jax.random.normal(ks[2], (r, N)),
-        )
-        got = lowrank_matmul_pallas(x, A, B, bm=16, bk=32, interpret=True)
-        want = (x @ A) @ B
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+    got = lowrank_matmul_pallas(x, A, B, bm=16, bk=32, interpret=True)
+    want = (x @ A) @ B
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
