@@ -37,7 +37,7 @@ the moment it emits its last token: a frozen slot keeps re-feeding its last
 (token, position) pair, which makes its cache writes idempotent, while its
 emit mask excludes everything after the stop from the drained results.
 
-Kernel backend selection goes through the unified dispatch runtime (PR 1):
+Kernel backend selection goes through the unified dispatch runtime:
 every prefill/fused-decode trace happens inside ``use_dispatch``, so
 ``--kernels`` applies per engine step exactly as it does to the static
 path; on TPU the decode step's attention lowers to the Pallas flash-decode
@@ -64,6 +64,29 @@ Pallas kernel and the gather-einsum oracle index physical pages
 indirectly, aliased page ids need zero kernel changes, and greedy outputs
 stay bit-identical to the unshared paged run: sharing relocates bytes,
 never changes what is attended.
+
+Profiler spans: each step and its phases open a host-side
+``jax.profiler.TraceAnnotation``, never inside a jitted program.  With no
+profiler session a span costs about a microsecond; under
+``jax.profiler.trace`` the spans land on the host plane on the same clock
+as the device's program executions, so device idle time can be put down to
+what the host was doing.  ``engine.step`` (stat ``step``) contains:
+
+  * ``engine.admit`` — scheduler admission, preemption placements, shedding;
+  * ``engine.cow_fork`` — one copy-on-write page fork;
+  * ``engine.prefill_group`` (stats ``G``, ``P``, ``uids``) — one grouped
+    prefill, containing ``engine.page_scatter`` (its K/V written into the
+    page pool) and ``engine.sample`` (its first tokens);
+  * ``engine.chunk`` (stats ``uid``, ``start``, ``n``) — one prefill chunk;
+  * ``engine.decode_block`` — one fused decode block from launch to the
+    end of its drain, containing ``engine.decode_sync``, where the host
+    waits for the block's outputs;
+  * ``engine.finish`` — one request finishing or quarantined, containing
+    ``engine.rematerialize``.
+
+A span that one request caused carries that request's ``uid``.  With the
+request's ``t_submit``, ``t_admit``, ``t_first`` and ``t_done`` this splits
+its life into queue, prefill and decode.
 """
 
 from __future__ import annotations
@@ -76,6 +99,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.bounds import CompressionCertificate, certify_tier
 from repro.core.lowrank import is_lowrank, slice_rank
@@ -146,6 +170,7 @@ class Request:
     uid: int = -1
     tokens: List[int] = dataclasses.field(default_factory=list)
     t_submit: float = 0.0
+    t_admit: float = 0.0  # placed in a slot; kept across preemption
     t_first: float = 0.0
     t_done: float = 0.0
     prefill_skipped: int = 0  # prompt tokens covered by shared prefix pages
@@ -300,9 +325,9 @@ class Engine:
     max_len`` worst-case reservation — so at equal KV bytes the paged pool
     admits strictly more concurrent requests whenever real footprints are
     below worst case.  ``kv_pages`` defaults to flat-equivalent capacity
-    (``n_slots * ceil(max_len / page_size)``); benchmarks lower it to bank
-    the savings.  Greedy outputs are bit-identical to the flat engine (the
-    block table only relocates bytes, never changes what is attended).
+    (``n_slots * ceil(max_len / page_size)``); deployments lower it to
+    bank the savings.  Greedy outputs are bit-identical to the flat engine
+    (the block table only relocates bytes, never changes what is attended).
 
     ``prefill_chunk`` (paged mode, families without cross-chunk prefill
     state) additionally splits prompts longer than the chunk into fixed
@@ -498,14 +523,28 @@ class Engine:
         )
         self.kv_bytes_capacity = sum(l.nbytes for l in cache_leaves)
         self._chunking: Dict[int, list] = {}  # slot -> [request, next_start, row]
-        self._chunk_jit = None
-        self._cow_fn = None  # jitted COW page copy (built on first fork)
-        self._prefill_jit = jax.jit(
-            lambda p, b, li: model.prefill(p, b, max_len, last_index=li)
+        # the (1, C) chunk-prefill program, shared by chunked prefill and
+        # _rematerialize; the cache is donated.  Profile readers match its
+        # module name, ``jit__lambda`` (bench/harness/trace.py): rename
+        # both together.
+        self._chunk_jit = jax.jit(
+            lambda p, c, t, bt, st, nr: model.prefill_chunk(p, c, t, bt, st, nr),
+            donate_argnums=(1,),
         )
+        self._cow_fn = None  # jitted COW page copy (built on first fork)
+
+        def grouped_prefill(p, b, li):
+            return model.prefill(p, b, max_len, last_index=li)
+
         # all-greedy fast path: skip the top-k/categorical machinery (two
         # (B,V) argsorts + B categorical draws) on the per-token hot path
-        self._argmax_jit = jax.jit(lambda l: jnp.argmax(l, axis=-1).astype(jnp.int32))
+        def first_token(logits):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        # named functions, so their executions show in a profile as
+        # ``jit_grouped_prefill`` and ``jit_first_token``
+        self._prefill_jit = jax.jit(grouped_prefill)
+        self._argmax_jit = jax.jit(first_token)
         self._base_key = jax.random.PRNGKey(0)
         self._fused_cache: Dict[bool, Any] = {}  # greedy? -> jitted block fn
 
@@ -522,7 +561,7 @@ class Engine:
         self._temps = np.zeros((n_slots,), np.float32)
         self._topks = np.zeros((n_slots,), np.int32)
         self._next_uid = 0
-        # perf accounting (benchmarks/serving.py --csv columns)
+        # perf accounting; callers read them around a window
         self.steps = 0  # device decode steps executed
         self.host_syncs = 0  # fused-block host round-trips
         self.decoded_tokens = 0  # tokens emitted by decode (excl. prefill)
@@ -709,7 +748,7 @@ class Engine:
             self.page_pool.free(grant.refs)
 
     def reset_prefix_cache(self) -> None:
-        """Forget every prefix-index entry (benchmark warmup boundary).
+        """Forget every prefix-index entry (warm-up boundary).
 
         Refcounts and live allocations are untouched — already-admitted
         slots keep their shared pages; only FUTURE admissions stop
@@ -817,7 +856,7 @@ class Engine:
         return self._bytes_resident + self._bytes_per_page * self.peak_pages_in_use
 
     def reset_counters(self):
-        """Re-arm the perf/accounting counters (benchmark warmup boundary).
+        """Re-arm the perf/accounting counters (warm-up boundary).
 
         Peaks re-arm to CURRENT usage, not zero: allocations held across
         the boundary (a request mid-chunked-prefill, live slots) would
@@ -890,48 +929,53 @@ class Engine:
         reqs = [req for _, req in group]
         lens = np.array([r.prompt.size for r in reqs], np.int32)
         G, P = self._prefill_shape(len(reqs), int(lens.max()))
-        toks = np.zeros((G, P), np.int32)
-        for i, r in enumerate(reqs):
-            toks[i, : r.prompt.size] = r.prompt
-        last_index = np.zeros((G,), np.int32)
-        last_index[: len(reqs)] = lens - 1
-        batch = {"tokens": jnp.asarray(toks)}
-        for name in reqs[0].extras:
-            rows = [r.extras[name] for r in reqs]
-            rows += [np.zeros_like(rows[0])] * (G - len(reqs))
-            batch[name] = jnp.asarray(np.stack(rows))
+        uids = " ".join(str(r.uid) for r in reqs)
+        with TraceAnnotation("engine.prefill_group", G=G, P=P, uids=uids):
+            toks = np.zeros((G, P), np.int32)
+            for i, r in enumerate(reqs):
+                toks[i, : r.prompt.size] = r.prompt
+            last_index = np.zeros((G,), np.int32)
+            last_index[: len(reqs)] = lens - 1
+            batch = {"tokens": jnp.asarray(toks)}
+            for name in reqs[0].extras:
+                rows = [r.extras[name] for r in reqs]
+                rows += [np.zeros_like(rows[0])] * (G - len(reqs))
+                batch[name] = jnp.asarray(np.stack(rows))
 
-        padded_reqs = reqs + [None] * (G - len(reqs))
-        tier = reqs[0].tier  # _admission_groups splits by tier
-        with use_dispatch(self._dcfg):
-            logits, part = self._prefill_jit(
-                self._tier_params[tier], batch, jnp.asarray(last_index)
-            )
-            if self.paged:
-                # dummy rows (and each slot's unallocated table tail) scatter
-                # to the trash page; allocated pages are fully overwritten
-                bt_rows = np.full((G, self.max_pages), self._trash, np.int32)
-                bt_rows[: len(slots)] = self._bt[slots]
-                pools = {k: v for k, v in self.cache.items() if k != "block_table"}
-                merged = _scatter_mixed(
-                    pools, part, self._paged_mask, slots, self.n_slots,
-                    jnp.asarray(bt_rows), self.page_size,
+            padded_reqs = reqs + [None] * (G - len(reqs))
+            tier = reqs[0].tier  # _admission_groups splits by tier
+            with use_dispatch(self._dcfg):
+                logits, part = self._prefill_jit(
+                    self._tier_params[tier], batch, jnp.asarray(last_index)
                 )
-                merged["block_table"] = self.cache["block_table"]
-                self.cache = merged
-                if self._share:
-                    # registration is DEFERRED to here (not admission) so a
-                    # match can never alias pages whose prefill has not
-                    # landed on device yet — same-round admissions simply
-                    # miss the sharing opportunity once
-                    for slot, req in group:
-                        backing = self._prefix[req.tier].register(
-                            req.prompt, self._bt[slot]
+                if self.paged:
+                    # dummy rows (and each slot's unallocated table tail)
+                    # scatter to the trash page; allocated pages are fully
+                    # overwritten
+                    bt_rows = np.full((G, self.max_pages), self._trash, np.int32)
+                    bt_rows[: len(slots)] = self._bt[slots]
+                    pools = {k: v for k, v in self.cache.items() if k != "block_table"}
+                    with TraceAnnotation("engine.page_scatter"):
+                        merged = _scatter_mixed(
+                            pools, part, self._paged_mask, slots, self.n_slots,
+                            jnp.asarray(bt_rows), self.page_size,
                         )
-                        self.page_pool.mark_indexed(backing)
-            else:
-                self.cache = _scatter_slots(self.cache, part, slots, self.n_slots)
-            first = self._sample(logits, padded_reqs, [0] * G)
+                    merged["block_table"] = self.cache["block_table"]
+                    self.cache = merged
+                    if self._share:
+                        # registration is DEFERRED to here (not admission) so
+                        # a match can never alias pages whose prefill has not
+                        # landed on device yet — same-round admissions simply
+                        # miss the sharing opportunity once
+                        for slot, req in group:
+                            backing = self._prefix[req.tier].register(
+                                req.prompt, self._bt[slot]
+                            )
+                            self.page_pool.mark_indexed(backing)
+                else:
+                    self.cache = _scatter_slots(self.cache, part, slots, self.n_slots)
+                with TraceAnnotation("engine.sample"):
+                    first = self._sample(logits, padded_reqs, [0] * G)
 
         now = time.perf_counter()
         finished = []
@@ -1028,7 +1072,9 @@ class Engine:
         if req is None:
             return None
         hit_eos = self.eos_token is not None and req.tokens and req.tokens[-1] == self.eos_token
-        if req.done or hit_eos:
+        if not (req.done or hit_eos):
+            return None
+        with TraceAnnotation("engine.finish", uid=req.uid):
             req.t_done = time.perf_counter()
             if self._share:
                 # Register the DECODE-FILLED pages before the slot releases:
@@ -1055,7 +1101,6 @@ class Engine:
                 self.page_pool.mark_indexed(backing)
             self._clear_slot(slot)
             return self._finalize(req)
-        return None
 
     def _sync_block_table(self):
         """Push host block-table edits to the device cache (pre-launch)."""
@@ -1063,8 +1108,9 @@ class Engine:
             self.cache["block_table"] = jnp.asarray(self._bt)
             self._bt_dirty = False
 
-    def _cow_fork(self, src: int, dst: int):
-        """Copy physical page ``src`` onto ``dst`` in every paged leaf.
+    def _cow_fork(self, src: int, dst: int, uid: int):
+        """Copy physical page ``src`` onto ``dst`` in every paged leaf, for
+        the admission of request ``uid``.
 
         The copy-on-write step of shared-prefix admission: the forked slot
         writes its last prompt token (and nothing else) into ``dst``, so
@@ -1089,7 +1135,7 @@ class Engine:
 
             self._cow_fn = jax.jit(cow, donate_argnums=(0,))
         pools = {k: v for k, v in self.cache.items() if k != "block_table"}
-        with use_dispatch(self._dcfg):
+        with TraceAnnotation("engine.cow_fork", uid=uid), use_dispatch(self._dcfg):
             pools = self._cow_fn(pools, jnp.int32(src), jnp.int32(dst))
         pools["block_table"] = self.cache["block_table"]
         self.cache = pools
@@ -1115,40 +1161,36 @@ class Engine:
         C = self._chunk_C
         plen = int(req.prompt.size)
         n = min(C, plen - start)
-        toks = np.zeros((1, C), np.int32)
-        toks[0, :n] = req.prompt[start : start + n]
-        if self._chunk_jit is None:
-            model = self.model
-            self._chunk_jit = jax.jit(
-                lambda p, c, t, bt, st, nr: model.prefill_chunk(p, c, t, bt, st, nr),
-                donate_argnums=(1,),
-            )
-        with use_dispatch(self._dcfg):
-            logits, self.cache = self._chunk_jit(
-                self._tier_params[req.tier],
-                self.cache,
-                jnp.asarray(toks),
-                jnp.asarray(row),
-                jnp.int32(start),
-                jnp.int32(n),
-            )
-        self.prefill_chunks += 1
-        start += n
-        if start < plen:
-            self._chunking[slot][1] = start
-            return [], n
-        del self._chunking[slot]
-        # last chunk landed: publish the row so the decode block (and its
-        # page writes) see the slot's pages from here on
-        self._bt[slot] = row
-        self._bt_dirty = True
-        if self._share:
-            # the prompt's full pages are now completely written on device:
-            # safe to offer them to future admissions
-            backing = self._prefix[req.tier].register(req.prompt, row)
-            self.page_pool.mark_indexed(backing)
-        first = self._sample(logits, [req], [0])
-        self._activate_slot(slot, req, plen, int(first[0]), time.perf_counter())
+        with TraceAnnotation("engine.chunk", uid=req.uid, start=start, n=n):
+            toks = np.zeros((1, C), np.int32)
+            toks[0, :n] = req.prompt[start : start + n]
+            with use_dispatch(self._dcfg):
+                logits, self.cache = self._chunk_jit(
+                    self._tier_params[req.tier],
+                    self.cache,
+                    jnp.asarray(toks),
+                    jnp.asarray(row),
+                    jnp.int32(start),
+                    jnp.int32(n),
+                )
+            self.prefill_chunks += 1
+            start += n
+            if start < plen:
+                self._chunking[slot][1] = start
+                return [], n
+            del self._chunking[slot]
+            # last chunk landed: publish the row so the decode block (and its
+            # page writes) see the slot's pages from here on
+            self._bt[slot] = row
+            self._bt_dirty = True
+            if self._share:
+                # the prompt's full pages are now completely written on
+                # device: safe to offer them to future admissions
+                backing = self._prefix[req.tier].register(req.prompt, row)
+                self.page_pool.mark_indexed(backing)
+            with TraceAnnotation("engine.sample"):
+                first = self._sample(logits, [req], [0])
+            self._activate_slot(slot, req, plen, int(first[0]), time.perf_counter())
         done = self._maybe_finish(slot)
         return ([done] if done is not None else []), n
 
@@ -1175,27 +1217,23 @@ class Engine:
         """
         C = self._chunk_C
         row = self._bt[slot]
-        if self._chunk_jit is None:
-            model = self.model
-            self._chunk_jit = jax.jit(
-                lambda p, c, t, bt, st, nr: model.prefill_chunk(p, c, t, bt, st, nr),
-                donate_argnums=(1,),
-            )
-        while start < end:
-            n = min(C, end - start)
-            toks = np.zeros((1, C), np.int32)
-            toks[0, :n] = seq[start : start + n]
-            with use_dispatch(self._dcfg):
-                _, self.cache = self._chunk_jit(
-                    self._tier_params[tier],
-                    self.cache,
-                    jnp.asarray(toks),
-                    jnp.asarray(row),
-                    jnp.int32(start),
-                    jnp.int32(n),
-                )
-            self.prefill_chunks += 1
-            start += n
+        uid = self._reqs[slot].uid
+        with TraceAnnotation("engine.rematerialize", uid=uid, start=start, end=end):
+            while start < end:
+                n = min(C, end - start)
+                toks = np.zeros((1, C), np.int32)
+                toks[0, :n] = seq[start : start + n]
+                with use_dispatch(self._dcfg):
+                    _, self.cache = self._chunk_jit(
+                        self._tier_params[tier],
+                        self.cache,
+                        jnp.asarray(toks),
+                        jnp.asarray(row),
+                        jnp.int32(start),
+                        jnp.int32(n),
+                    )
+                self.prefill_chunks += 1
+                start += n
 
     # ------------------------------------------------------------------ #
     # the fused decode block (device-resident inner loop)
@@ -1288,33 +1326,35 @@ class Engine:
         health signal the cluster's heartbeat monitor consumes, instead of
         a stalled fused block hanging ``run()`` silently.
         """
-        if self.watchdog is None:
-            return self._step_inner()
-        t0 = time.monotonic()
-        finished = self._step_inner()
-        if self.watchdog.observe(self._step_idx, time.monotonic() - t0):
-            self.straggler_flags += 1
-            if self.on_event is not None:
-                self.on_event(
-                    "straggler",
-                    {"step": self._step_idx,
-                     "seconds": round(self.watchdog.durations[-1], 6),
-                     "median_s": round(self.watchdog.median, 6)},
-                )
-        return finished
+        self._step_idx += 1
+        with TraceAnnotation("engine.step", step=self._step_idx):
+            if self.watchdog is None:
+                return self._step_inner()
+            t0 = time.monotonic()
+            finished = self._step_inner()
+            if self.watchdog.observe(self._step_idx, time.monotonic() - t0):
+                self.straggler_flags += 1
+                if self.on_event is not None:
+                    self.on_event(
+                        "straggler",
+                        {"step": self._step_idx,
+                         "seconds": round(self.watchdog.durations[-1], 6),
+                         "median_s": round(self.watchdog.median, 6)},
+                    )
+            return finished
 
     def _step_inner(self) -> List[Request]:
         finished: List[Request] = []
-        self._step_idx += 1
         if self.injector is not None:
             self.injector.on_step(self._step_idx)
 
-        placed = self.scheduler.admit()
-        if self.preempt:
-            placed.extend(self._preempt_for_waiters())
-        # deadline-expired waiters shed by admission surface as finished
-        # requests with status "shed" and a structured rejection attached
-        finished.extend(self.scheduler.drain_shed())
+        with TraceAnnotation("engine.admit"):
+            placed = self.scheduler.admit()
+            if self.preempt:
+                placed.extend(self._preempt_for_waiters())
+            # deadline-expired waiters shed by admission surface as finished
+            # requests with status "shed" and a structured rejection attached
+            finished.extend(self.scheduler.drain_shed())
         if placed:
             # page peaks are tracked INSIDE the allocator at every
             # allocation-changing site; only the admitted-request peak is
@@ -1342,7 +1382,7 @@ class Engine:
                 # prompt covered), COW-fork it first so the re-write of the
                 # final prompt token never lands in shared storage.
                 if grant.cow is not None:
-                    self._cow_fork(*grant.cow)
+                    self._cow_fork(*grant.cow, uid=req.uid)
                 self._chunking[slot] = [req, grant.start, row]
             elif chunking and req.prompt.size > self.prefill_chunk:
                 # The slot's DEVICE table row stays on trash until the last
@@ -1386,7 +1426,7 @@ class Engine:
         # position with its tier's params before anything attends to it
         # (write-before-read), so attention-family state self-repairs;
         # recurrent families are rejected at construction.  Single-tier
-        # engines take exactly one pass — the PR-4 hot path unchanged.
+        # engines take exactly one pass.
         slot_tiers = np.array(
             [r.tier if r is not None else 0 for r in self._reqs], np.int32
         )
@@ -1398,64 +1438,67 @@ class Engine:
 
     def _fused_pass(self, tier, mask, poison_slot, poison_rel) -> List[Request]:
         """Run one fused decode block over the slots in ``mask`` (one tier)."""
-        self._sync_block_table()
-        greedy = not (self._temps[mask] > 0).any()
-        fused = self._fused_fn(greedy)
-        with use_dispatch(self._dcfg):
-            (
-                self.cache,
-                tokens_d,
-                pos_d,
-                active_d,
-                emitted_d,
-                quar_d,
-                toks_d,
-                emits_d,
-            ) = fused(
-                self._tier_params[tier],
-                self.cache,
-                jnp.asarray(self._tokens),
-                jnp.asarray(self._pos),
-                jnp.asarray(mask),
-                jnp.asarray(self._emitted),
-                jnp.asarray(self._max_new),
-                jnp.asarray(self._seeds),
-                jnp.asarray(self._temps),
-                jnp.asarray(self._topks),
-                self._base_key,
-                jnp.int32(poison_slot),
-                jnp.int32(poison_rel),
-            )
-        # THE host sync for this block: drain the (n_steps, n_slots) emit
-        # stack plus the final per-slot state in one transfer batch.
-        toks = np.asarray(toks_d)
-        emits = np.asarray(emits_d)
-        quar = np.asarray(quar_d)
-        # merge ONLY this pass's rows into the host mirrors: rows of other
-        # tiers were masked inactive for this pass, and their final device
-        # "active" (False) must not clobber the real liveness state
-        self._tokens[mask] = np.asarray(tokens_d)[mask]
-        self._pos[mask] = np.asarray(pos_d)[mask]
-        self._active[mask] = np.asarray(active_d)[mask]
-        self._emitted[mask] = np.asarray(emitted_d)[mask]
-        self.steps += self.decode_block
-        self.host_syncs += 1
-        self.decoded_tokens += int(emits.sum())
+        with TraceAnnotation("engine.decode_block", tier=tier, slots=int(mask.sum())):
+            self._sync_block_table()
+            greedy = not (self._temps[mask] > 0).any()
+            fused = self._fused_fn(greedy)
+            with use_dispatch(self._dcfg):
+                (
+                    self.cache,
+                    tokens_d,
+                    pos_d,
+                    active_d,
+                    emitted_d,
+                    quar_d,
+                    toks_d,
+                    emits_d,
+                ) = fused(
+                    self._tier_params[tier],
+                    self.cache,
+                    jnp.asarray(self._tokens),
+                    jnp.asarray(self._pos),
+                    jnp.asarray(mask),
+                    jnp.asarray(self._emitted),
+                    jnp.asarray(self._max_new),
+                    jnp.asarray(self._seeds),
+                    jnp.asarray(self._temps),
+                    jnp.asarray(self._topks),
+                    self._base_key,
+                    jnp.int32(poison_slot),
+                    jnp.int32(poison_rel),
+                )
+            # THE host sync for this block: drain the (n_steps, n_slots) emit
+            # stack plus the final per-slot state in one transfer batch.
+            with TraceAnnotation("engine.decode_sync"):
+                toks = np.asarray(toks_d)
+                emits = np.asarray(emits_d)
+                quar = np.asarray(quar_d)
+                # merge ONLY this pass's rows into the host mirrors: rows of
+                # other tiers were masked inactive for this pass, and their
+                # final device "active" (False) must not clobber the real
+                # liveness state
+                self._tokens[mask] = np.asarray(tokens_d)[mask]
+                self._pos[mask] = np.asarray(pos_d)[mask]
+                self._active[mask] = np.asarray(active_d)[mask]
+                self._emitted[mask] = np.asarray(emitted_d)[mask]
+            self.steps += self.decode_block
+            self.host_syncs += 1
+            self.decoded_tokens += int(emits.sum())
 
-        finished: List[Request] = []
-        for s in np.nonzero(emits.any(axis=0) | quar)[0]:
-            s = int(s)
-            req = self._reqs[s]
-            for tok, emit in zip(toks[:, s], emits[:, s]):
-                if emit:
-                    req.tokens.append(int(tok))
-            if quar[s]:
-                finished.append(self._quarantine_slot(s))
-                continue
-            done = self._maybe_finish(s)
-            if done is not None:
-                finished.append(done)
-        return finished
+            finished: List[Request] = []
+            for s in np.nonzero(emits.any(axis=0) | quar)[0]:
+                s = int(s)
+                req = self._reqs[s]
+                for tok, emit in zip(toks[:, s], emits[:, s]):
+                    if emit:
+                        req.tokens.append(int(tok))
+                if quar[s]:
+                    finished.append(self._quarantine_slot(s))
+                    continue
+                done = self._maybe_finish(s)
+                if done is not None:
+                    finished.append(done)
+            return finished
 
     # ------------------------------------------------------------------ #
     # overload machinery: preemption, quarantine, session close
@@ -1647,17 +1690,18 @@ class Engine:
         back a future match — they just free for clean reuse.
         """
         req = self._reqs[slot]
-        req.t_done = time.perf_counter()
-        req.status = "error"
-        req.error = "non-finite logits during decode"
-        self._clear_slot(slot)
-        self.quarantined += 1
-        if self.on_event is not None:
-            self.on_event(
-                "quarantine",
-                {"uid": req.uid, "emitted": len(req.tokens), "slot": slot},
-            )
-        return self._finalize(req)
+        with TraceAnnotation("engine.finish", uid=req.uid):
+            req.t_done = time.perf_counter()
+            req.status = "error"
+            req.error = "non-finite logits during decode"
+            self._clear_slot(slot)
+            self.quarantined += 1
+            if self.on_event is not None:
+                self.on_event(
+                    "quarantine",
+                    {"uid": req.uid, "emitted": len(req.tokens), "slot": slot},
+                )
+            return self._finalize(req)
 
     def drop_session(self, prompt) -> int:
         """Close an abandoned conversation branch NOW: drop its prefix-index

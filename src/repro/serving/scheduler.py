@@ -843,6 +843,8 @@ class Scheduler:
         self.queue = kept
 
     def admit(self) -> List[Tuple[int, object]]:
+        """Place waiting requests in free slots, FIFO; returns this round's
+        (slot, request) placements, each request's ``t_admit`` stamped."""
         if self.policy is not None and self.policy.shed_deadlines:
             self._shed_expired()
         placed = []
@@ -868,7 +870,12 @@ class Scheduler:
                 self.slot_pages[slot] = grant
             else:
                 slot = self.allocator.alloc()
-            placed.append((slot, self.queue.popleft()))
+            req = self.queue.popleft()
+            if getattr(req, "t_admit", None) == 0.0:
+                # set once: a request re-admitted elsewhere after a
+                # failover keeps the time it first got a slot
+                req.t_admit = time.perf_counter()
+            placed.append((slot, req))
         return placed
 
     def release(self, slot: int) -> None:
