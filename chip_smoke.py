@@ -92,15 +92,42 @@ def check_kernels(cfg, seed, max_len):
     bt = bt.reshape(N_SLOTS, n_tbl).astype(jnp.int32)
     # a dead slot, page edges, and the deepest table
     n_valid = jnp.array([0, 1, PAGE - 1, PAGE, PAGE + 1, 600, max_len, n_tbl * PAGE], jnp.int32)
-    got = paged_decode_attention_pallas(q, kp, vp, bt, n_valid)
-    want = ref.paged_decode_attention_ref(q, kp, vp, bt, n_valid)
-    # Both round the softmax probabilities to bf16 before the PV matmul, at
-    # different points (kernel: unnormalized, per page; reference: once,
-    # normalized), each at most 2^-8 relative, so they differ by at most
-    # 2^-7 * max|v|; rounding each output to bf16 adds 2^-7 * |out|.  The
-    # bound is twice that, for f32 sums taken in a different order.
-    agree("paged_decode_attention", got, want,
-          2.0**-6 * (jnp.max(jnp.abs(vp)) + jnp.abs(want.astype(jnp.float32))))
+
+    def paged_agree(name, q, kp, vp, bt, n_valid):
+        got = paged_decode_attention_pallas(q, kp, vp, bt, n_valid)
+        want = ref.paged_decode_attention_ref(q, kp, vp, bt, n_valid)
+        # Both round the softmax probabilities to bf16 before the PV matmul,
+        # at different points (kernel: unnormalized, per page; reference:
+        # once, normalized), each at most 2^-8 relative, so they differ by
+        # at most 2^-7 * max|v|; rounding each output to bf16 adds
+        # 2^-7 * |out|.  The bound is twice that, for f32 sums taken in a
+        # different order.
+        agree(name, got, want,
+              2.0**-6 * (jnp.max(jnp.abs(vp)) + jnp.abs(want.astype(jnp.float32))))
+
+    paged_agree("paged_decode_attention", q, kp, vp, bt, n_valid)
+
+    # The chat benchmark's geometry: 32 slots over a 2,560-token table of
+    # 20 pages, 26 of them empty (n_valid 1, every entry on the trash page)
+    # and 6 live, so the walk stops early in every slot but the deepest.
+    slots, cell_tbl = 32, 20
+    live_nv = [300, 700, 1100, 1536, 2048, cell_tbl * PAGE]
+    live_pages = [-(-n // PAGE) for n in live_nv]
+    P = sum(live_pages) + 1
+    trash = P - 1
+    kc = jax.random.split(jax.random.PRNGKey(seed + 3), 5)
+    live = jax.random.permutation(kc[0], slots)[: len(live_nv)].tolist()
+    order = iter(jax.random.permutation(kc[1], trash).tolist())
+    bt = [[trash] * cell_tbl for _ in range(slots)]
+    n_valid = [1] * slots
+    for s, n, k in zip(live, live_nv, live_pages):
+        bt[s][:k] = [next(order) for _ in range(k)]
+        n_valid[s] = n
+    q = jax.random.normal(kc[2], (slots, 1, H, hd), bf16)
+    kp = jax.random.normal(kc[3], (P, PAGE, KV, hd), bf16)
+    vp = jax.random.normal(kc[4], (P, PAGE, KV, hd), bf16)
+    paged_agree(f"paged_decode_attention B={slots} n_tbl={cell_tbl} (6 live)", q, kp, vp,
+                jnp.asarray(bt, jnp.int32), jnp.asarray(n_valid, jnp.int32))
 
     d = cfg.d_model
     r = CompressionPolicy(alpha=ALPHA, q=Q).rank_for(d, d)
@@ -206,6 +233,8 @@ def serve(label, model, params, prompts, max_len):
           f"{first_s:.3f} s (compiles included), warm rerun {steady_s:.3f} s "
           f"= {n_tok / steady_s:.1f} tok/s; prompt tokens skipped by sharing "
           f"{eng.skipped_prefill_tokens}", flush=True)
+    print(f"[{label}] paged attention pages walked {eng.attn_pages_walked} of "
+          f"{eng.attn_pages_offered} offered, both runs", flush=True)
     print(f"[{label}] dispatch paths: "
           + ", ".join(f"{op}/{path} x{n}" for (op, path), n in sorted(hits.items())),
           flush=True)

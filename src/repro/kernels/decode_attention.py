@@ -1,20 +1,35 @@
 """Pallas TPU kernel: flash-decode — one-token GQA attention over a KV cache.
 
 The serving decode hot path: every active slot attends its single new query
-against its whole cache row.  The kernel streams the cache's SEQUENCE dim
-through VMEM in blocks (split-KV online softmax: running (m, l, acc) live in
-scratch across the sequential grid axis), so no (B, H, S) score tensor is
-ever materialized and the cache itself is never copied or transposed — the
-BlockSpec index maps read (bs, KV, hd) tiles straight out of the
-(B, S, KV, hd) pool layout.
+against its cache row.  Both kernels stream the cache's SEQUENCE dim
+through VMEM in blocks (split-KV online softmax: a running (m, l, acc) per
+KV head), so no (B, H, S) score tensor is ever materialized and the cache
+itself is never copied or transposed.
 
-Tiling: the grid is (B, S/bs) and each program carries ALL KV heads of one
-sequence block.  Mosaic requires the last two dims of every block to be
-(8, 128)-divisible or equal to the array's; a block with one KV head out of
-KV > 1 breaks that rule on the second-minor axis, so the (KV, hd) pair is
-always taken whole.  The body loops over the KV heads statically and, per
-head, computes all ``G = H // KV`` query heads that share it, so the
-(G, hd) @ (hd, bs) score matmul reads K once per KV head, not per query head.
+Flat kernel: the grid is (B, S/bs), the running state lives in scratch
+across the sequential axis, and the BlockSpec index maps read (bs, KV, hd)
+tiles straight out of the (B, S, KV, hd) cache.  Each program carries ALL
+KV heads of one sequence block.  Mosaic requires the last two dims of every
+block to be (8, 128)-divisible or equal to the array's; a block with one KV
+head out of KV > 1 breaks that rule on the second-minor axis, so the
+(KV, hd) pair is always taken whole.  The body loops over the KV heads
+statically and, per head, computes all ``G = H // KV`` query heads that
+share it, so the (G, hd) @ (hd, bs) score matmul reads K once per KV head,
+not per query head.
+
+Paged kernel: the grid is (B,), one step per slot, and the slot's pages are
+copied out of the shared (P, page, KV, hd) pool by hand, double-buffered,
+through its block table.  The walk stops at the slot's last live page,
+``max(ceil(n_valid / page), 1)`` pages: a decode step pays for the pages
+its slots hold, not for ``B x n_tbl``.  A (B, n_tbl) grid could stop the
+copies too: an index map clamped to the last live page names, on every
+later step, the block already resident, and the pipeline issues no copy
+for a block index that repeats.  But each of those steps would still cost
+the grid's fixed per-step overhead, which at a few live pages in a deep
+table is most of the kernel's time; the per-slot loop costs none.  The
+same per-head math runs on each page, in the same order, so the result is
+bit for bit the one a walk over the whole table gives (a page wholly past
+``n_valid`` would leave (m, l, acc) exactly as they were).
 
 Masking is STRICT per slot.  The flat kernel takes a ``valid`` (B, S) mask
 (built by the caller from per-slot ``n_valid`` or a ring-buffer
@@ -56,6 +71,35 @@ NEG_INF = -1e30
 VMEM_ANALYSIS_BOUNDS = {"G": 16, "KV": 16, "hd": 256, "vd": 256, "page": 128}
 
 
+def _scaled_q(q, dtype, scale):
+    # Same dtype discipline as the reference: scale in fp32, cast back to
+    # the cache dtype, accumulate scores in fp32 on the MXU.
+    return (q.astype(jnp.float32) * scale).astype(dtype)
+
+
+def _softmax_step(qs, k, v, live, m_prev, l_prev, acc_prev):
+    """One online-softmax step of one KV head over a (bs, hd) block.
+
+    ``qs`` is the head's (G, hd) scaled query, ``live`` the (1, bs)
+    validity of the block's positions; returns the updated running
+    (G, 1) max, (G, 1) denominator and (G, vd) numerator.
+    """
+    s = jax.lax.dot_general(
+        qs, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # (G, bs)
+    s = jnp.where(live, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    # exp(s - m) is 1, not 0, on fully-masked rows (m == NEG_INF);
+    # re-masking keeps l at 0 there so empty slots flush to zeros.
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_new = acc_prev * corr + jnp.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32
+    )
+    return m_new, l_new, acc_new
+
+
 def _attend_block(q_ref, k_ref, v_ref, live, m_ref, l_ref, acc_ref, *, scale):
     """One online-softmax step over a (bs, KV, hd) block, every KV head.
 
@@ -63,28 +107,12 @@ def _attend_block(q_ref, k_ref, v_ref, live, m_ref, l_ref, acc_ref, *, scale):
     holds one (G, 1) / (G, 1) / (G, vd) running (m, l, acc) per KV head.
     """
     for h in range(q_ref.shape[1]):
-        q = q_ref[0, h]  # (G, hd)
         k = k_ref[0, :, h, :]  # (bs, hd)
         v = v_ref[0, :, h, :]  # (bs, vd)
-        # Same dtype discipline as the reference: scale in fp32, cast back to
-        # the cache dtype, accumulate scores in fp32 on the MXU.
-        qs = (q.astype(jnp.float32) * scale).astype(k.dtype)
-        s = jax.lax.dot_general(
-            qs, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (G, bs)
-        s = jnp.where(live, s, NEG_INF)
-
-        m_prev, l_prev = m_ref[h], l_ref[h]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # exp(s - m) is 1, not 0, on fully-masked rows (m == NEG_INF);
-        # re-masking keeps l at 0 there so empty slots flush to zeros.
-        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[h] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[h] = acc_ref[h] * corr + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        m_ref[h], l_ref[h], acc_ref[h] = _softmax_step(
+            _scaled_q(q_ref[0, h], k.dtype, scale), k, v, live,
+            m_ref[h], l_ref[h], acc_ref[h],
         )
-        m_ref[h] = m_new
 
 
 def _init_scratch(m_ref, l_ref, acc_ref):
@@ -161,19 +189,71 @@ def decode_attention_pallas(
 
 
 def _paged_decode_kernel(
-    bt_ref, nv_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, n_s, page
+    bt_ref, nv_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, k_sem, v_sem, buf_ref,
+    *, scale, page, n_tbl,
 ):
-    # The block table was consumed by the BlockSpec index maps (scalar
-    # prefetch); n_valid masks in the body.  Otherwise this is EXACTLY the
-    # flat split-KV online softmax — one page of the slot's cache per
-    # sequential grid step.
-    del bt_ref
-    b, j = pl.program_id(0), pl.program_id(1)
-    pl.when(j == 0)(lambda: _init_scratch(m_ref, l_ref, acc_ref))
-    pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-    live = pos < nv_ref[b]  # (1, page)
-    _attend_block(q_ref, k_ref, v_ref, live, m_ref, l_ref, acc_ref, scale=scale)
-    pl.when(j == n_s - 1)(lambda: _flush(o_ref, l_ref, acc_ref))
+    # One grid step per slot.  The slot's pages up to its last live one are
+    # copied HBM -> VMEM by hand into two alternating buffers: page i + 1
+    # (or, after the slot's last page, the NEXT slot's first) is in flight
+    # while page i is attended.  The running (m, l, acc) of every KV head
+    # and the scaled queries stay loop values, not VMEM round-trips.
+    b, n_b = pl.program_id(0), pl.num_programs(0)
+    KV, G = q_ref.shape[1], q_ref.shape[2]
+    vd = o_ref.shape[-1]
+
+    def copies(s, i, buf):
+        page_id = bt_ref[s, i]
+        return (
+            pltpu.make_async_copy(k_hbm.at[page_id], k_buf.at[buf], k_sem.at[buf]),
+            pltpu.make_async_copy(v_hbm.at[page_id], v_buf.at[buf], v_sem.at[buf]),
+        )
+
+    def start(s, i, buf):
+        for c in copies(s, i, buf):
+            c.start()
+
+    @pl.when(b == 0)
+    def _():
+        buf_ref[0] = 0
+        start(0, 0, 0)
+
+    nv = nv_ref[b]
+    # pages through the slot's last live one; a slot with nothing valid
+    # still walks (and fully masks) one page, so every slot flushes
+    n_pages = jnp.minimum(jnp.maximum(pl.cdiv(nv, page), 1), n_tbl)
+    qs = [_scaled_q(q_ref[0, h], k_buf.dtype, scale) for h in range(KV)]
+
+    def attend_page(i, carry):
+        buf, state = carry
+        nxt = 1 - buf
+
+        @pl.when(i + 1 < n_pages)
+        def _():
+            start(b, i + 1, nxt)
+
+        @pl.when((i + 1 == n_pages) & (b + 1 < n_b))
+        def _():
+            start(b + 1, 0, nxt)
+
+        for c in copies(b, i, buf):
+            c.wait()
+        live = i * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1) < nv
+        state = [
+            _softmax_step(qs[h], k_buf[buf, :, h, :], v_buf[buf, :, h, :], live, *state[h])
+            for h in range(KV)
+        ]
+        return nxt, state
+
+    empty = (
+        jnp.full((G, 1), NEG_INF, jnp.float32),
+        jnp.zeros((G, 1), jnp.float32),
+        jnp.zeros((G, vd), jnp.float32),
+    )
+    buf_ref[0], state = jax.lax.fori_loop(
+        0, n_pages, attend_page, (buf_ref[0], [empty] * KV)
+    )
+    for h, (_, l, acc) in enumerate(state):
+        o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -188,21 +268,29 @@ def paged_decode_attention_pallas(
 ):
     """Block-table flash-decode: one-token GQA attention over a PAGED cache.
 
-    Identical split-KV online-softmax / GQA-tiling structure to
-    :func:`decode_attention_pallas`, but the cache's sequence dim is
-    virtualized: the grid's sequential axis walks the slot's BLOCK TABLE
-    (one fixed-size page per step), and the K/V BlockSpec index maps — with
-    the table as a scalar-prefetch operand — DMA each (page, KV, hd) page
-    straight out of the shared physical pool.  No (B, S, KV, hd) per-slot
-    gather is ever materialized, which is the whole point: the flat engine's
-    worst-case per-slot reservation becomes a pool of pages shared by every
-    slot.
+    The split-KV online softmax and GQA tiling of
+    :func:`decode_attention_pallas`, over a cache whose sequence dim is
+    virtualized: each slot walks its BLOCK TABLE one fixed-size page at a
+    time, copying each (page, KV, hd) page straight out of the shared
+    physical pool.  No (B, S, KV, hd) per-slot gather is ever materialized,
+    which is the whole point: the flat engine's worst-case per-slot
+    reservation becomes a pool of pages shared by every slot.
+
+    The walk stops at each slot's last live page, ``max(ceil(n_valid /
+    page), 1)`` pages, with ``n_valid`` and the block table as
+    scalar-prefetch operands: pages past it are neither copied nor
+    attended, so a decode step costs the pages its slots hold, not
+    ``B x n_tbl`` (the module docstring says why the grid is one step per
+    slot and not one per page).  Skipping a page that lies wholly past
+    ``n_valid`` changes no output bit (its scores would be masked, its
+    probabilities 0, the running max and sums multiplied by exactly 1), so
+    the result is the one a walk over the whole table gives.
 
     Entries of ``block_table`` beyond a slot's allocation may point at the
-    pool's trash page; ``n_valid`` (the second scalar-prefetch operand)
-    masks their positions, so trash contents are never observed
-    (fully-masked rows produce zeros, same contract as the flat kernel).
-    The gather-einsum oracle is kernels/ref.paged_decode_attention_ref.
+    pool's trash page; ``n_valid`` masks positions past it inside the last
+    live page, so trash contents are never observed (fully-masked rows
+    produce zeros, same contract as the flat kernel).  The gather-einsum
+    oracle is kernels/ref.paged_decode_attention_ref.
     """
     B, one, H, hd = q.shape
     if one != 1:
@@ -216,27 +304,30 @@ def paged_decode_attention_pallas(
     G = H // KV
     n_tbl = block_table.shape[1]
     qg = q.reshape(B, KV, G, hd)
-    grid = (B, n_tbl)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block table steers the K/V maps; n_valid masks
-        grid=grid,
+        num_scalar_prefetch=2,  # block table and n_valid steer the page walk
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, KV, G, hd), lambda b, j, bt, nv: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page, KV, hd), lambda b, j, bt, nv: (bt[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, KV, vd), lambda b, j, bt, nv: (bt[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, KV, G, hd), lambda b, bt, nv: (b, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # pools stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, KV, G, vd), lambda b, j, bt, nv: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, G, vd), lambda b, bt, nv: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, vd), jnp.float32),
+            pltpu.VMEM((2, page, KV, hd), k_pool.dtype),
+            pltpu.VMEM((2, page, KV, vd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),  # buffer that holds the next slot's first page
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=hd**-0.5, n_s=n_tbl, page=page),
+        functools.partial(_paged_decode_kernel, scale=hd**-0.5, page=page, n_tbl=n_tbl),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, vd), q.dtype),
+        # slots run in order: each one's last page prefetches the next's first
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_table.astype(jnp.int32), n_valid.astype(jnp.int32), qg, k_pool, v_pool)
     return out.reshape(B, 1, H, vd)

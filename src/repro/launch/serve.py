@@ -334,10 +334,13 @@ def main(argv=None):
         steps_t = sum(e.steps for e in engines)
         syncs_t = sum(e.host_syncs for e in engines)
         dec_t = sum(e.decoded_tokens for e in engines)
+        walked_t = sum(e.attn_pages_walked for e in engines)
+        offered_t = sum(e.attn_pages_offered for e in engines)
         print(f"latency {lat_s} "
               f"decode_steps={steps_t} host_syncs={syncs_t} "
               f"tok_per_sync={dec_t / max(syncs_t, 1):.1f} "
-              f"util={sum(e.batch_utilization for e in engines) / len(engines):.3f}")
+              f"util={sum(e.batch_utilization for e in engines) / len(engines):.3f} "
+              f"attn_pages_walked={walked_t} attn_pages_offered={offered_t}")
         for i, e in enumerate(engines):
             tag = f"[paged r{i}]" if cluster is not None else "[paged]"
             if not e.paged:

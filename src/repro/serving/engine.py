@@ -564,6 +564,11 @@ class Engine:
         # perf accounting; callers read them around a window
         self.steps = 0  # device decode steps executed
         self.host_syncs = 0  # fused-block host round-trips
+        # paged decode attention, per layer and decode step, summed over
+        # fused blocks: pages the kernel walks (each slot up to its last
+        # live page) and pages a whole-table walk would take
+        self.attn_pages_walked = 0
+        self.attn_pages_offered = 0
         self.decoded_tokens = 0  # tokens emitted by decode (excl. prefill)
         self.peak_active = 0  # max concurrently admitted requests
         self.prefill_chunks = 0  # chunked-prefill chunks executed
@@ -864,6 +869,7 @@ class Engine:
         under-reporting ``kv_bytes_peak``.
         """
         self.steps = self.host_syncs = self.decoded_tokens = 0
+        self.attn_pages_walked = self.attn_pages_offered = 0
         self.prefill_chunks = 0
         self.shared_page_hits = self.cow_forks = self.shared_admissions = 0
         self.skipped_prefill_tokens = 0
@@ -1436,12 +1442,26 @@ class Engine:
             finished.extend(self._fused_pass(tier, mask, poison_slot, poison_rel))
         return finished
 
+    def _count_attn_pages(self):
+        """Add one fused block's pages to the attention page counters.
+
+        Every slot's row goes through paged decode attention, which walks
+        ``ceil(n_valid / page)`` pages of the slot's table, ``n_valid`` =
+        pos + 1 (counted from the launch's positions), against the
+        ``max_pages`` of a whole-table walk.
+        """
+        walked = np.minimum(-(-(self._pos + 1) // self.page_size), self.max_pages)
+        self.attn_pages_walked += self.decode_block * int(walked.sum())
+        self.attn_pages_offered += self.decode_block * self.n_slots * self.max_pages
+
     def _fused_pass(self, tier, mask, poison_slot, poison_rel) -> List[Request]:
         """Run one fused decode block over the slots in ``mask`` (one tier)."""
         with TraceAnnotation("engine.decode_block", tier=tier, slots=int(mask.sum())):
             self._sync_block_table()
             greedy = not (self._temps[mask] > 0).any()
             fused = self._fused_fn(greedy)
+            if self.paged:
+                self._count_attn_pages()
             with use_dispatch(self._dcfg):
                 (
                     self.cache,

@@ -14,7 +14,10 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import paged_decode_attention_pallas
+from repro.kernels.decode_attention import (
+    decode_attention_pallas,
+    paged_decode_attention_pallas,
+)
 from repro.runtime import dispatch
 from repro.runtime.dispatch import DECODE_MIN_SEQ, DispatchConfig, use_dispatch
 
@@ -118,6 +121,75 @@ def test_paged_fully_masked_rows_are_zero(dtype):
         assert np.abs(got[1]).sum() > 0
 
 
+def _engine_layout(n_tbl, page, KV, G, hd, dtype, seed):
+    """One slot for each n_valid in (0, 1, page - 1, page, page + 1, full),
+    laid out as the engine lays them: live pages at scattered physical ids;
+    table entries past them alternate between the trash page (finite
+    poison, as dummy-row scatters leave it) and in-range pages that no slot
+    owns, filled with NaN.  A walk that copied and attended one of those
+    would turn the slot's output NaN (0 * NaN in the PV matmul).  The
+    n_valid 0 slot's row is all trash.  Returns the kernel's inputs and
+    the pools with NaN made finite poison, for the oracles."""
+    n_valid = np.array([0, 1, page - 1, page, page + 1, n_tbl * page], np.int32)
+    B = n_valid.size
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = _rand(ks[0], (B, 1, KV * G, hd), dtype)
+    n_live = -(-n_valid // page)
+    P = 2 * B * n_tbl + 1  # live pages, unowned pages, trash
+    trash = P - 1
+    rng = np.random.default_rng(seed)
+    ids = iter(rng.permutation(P - 1).tolist())
+    k_pool = np.full((P, page, KV, hd), 1e4, np.float32)
+    v_pool = np.full((P, page, KV, hd), 1e4, np.float32)
+    k_live = np.asarray(_rand(ks[1], (P, page, KV, hd), jnp.float32))
+    v_live = np.asarray(_rand(ks[2], (P, page, KV, hd), jnp.float32))
+    bt = np.full((B, n_tbl), trash, np.int32)
+    unowned = []
+    for b in range(B):
+        for j in range(n_live[b]):
+            bt[b, j] = pid = next(ids)
+            k_pool[pid], v_pool[pid] = k_live[pid], v_live[pid]
+        if n_valid[b]:
+            for j in range(n_live[b], n_tbl, 2):
+                bt[b, j] = pid = next(ids)
+                unowned.append(pid)
+    k_nan, v_nan = k_pool.copy(), v_pool.copy()
+    k_nan[unowned] = v_nan[unowned] = np.nan
+    return (
+        q,
+        jnp.asarray(k_nan, dtype), jnp.asarray(v_nan, dtype),
+        jnp.asarray(k_pool, dtype), jnp.asarray(v_pool, dtype),
+        jnp.asarray(bt), jnp.asarray(n_valid),
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("G", [1, 3, 4, 8])
+def test_paged_kernel_walks_only_live_pages(G, dtype):
+    """Pages past a slot's last live page are never copied or attended:
+    NaN there leaves every output finite, the n_valid 0 row is zeros, and
+    the result matches the gather oracle.  In float32 it is bit for bit
+    what a walk over the whole table gives (the flat kernel, one page per
+    block, over the gathered cache).  An odd table length (5) makes the
+    double-buffered walk end on both buffers."""
+    n_tbl, page, KV, hd = 5, 16, 2, 16
+    q, k_nan, v_nan, kp, vp, bt, n_valid = _engine_layout(n_tbl, page, KV, G, hd, dtype, seed=G)
+    got = paged_decode_attention_pallas(q, k_nan, v_nan, bt, n_valid, interpret=True)
+    got32 = np.asarray(got, np.float32)
+    assert np.isfinite(got32).all()
+    np.testing.assert_array_equal(got32[0], np.zeros_like(got32[0]))
+    want = ref.paged_decode_attention_ref(q, kp, vp, bt, n_valid)
+    np.testing.assert_allclose(got32, np.asarray(want, np.float32), **TOL[dtype])
+    if dtype == jnp.float32:
+        S = n_tbl * page
+        valid = jnp.arange(S)[None, :] < n_valid[:, None]
+        whole = decode_attention_pallas(
+            q, ref.gather_pages(kp, bt), ref.gather_pages(vp, bt), valid,
+            bs=page, interpret=True,
+        )
+        np.testing.assert_array_equal(got32, np.asarray(whole))
+
+
 # --------------------------------------------------------------------------- #
 # dispatch routing
 # --------------------------------------------------------------------------- #
@@ -167,3 +239,26 @@ def test_paged_engine_decode_runs_through_dispatch_counter():
     hits = dispatch.counters_by_path()
     assert hits.get(("paged_decode_attention", "xla"), 0) >= 1  # CPU auto -> gather
     assert hits.get(("decode_attention", "xla"), 0) == 0
+
+
+def test_engine_counts_attention_pages_walked():
+    """One fused block: every slot's row walks ceil((pos + 1) / page) pages
+    of its table, per decode step; a whole-table walk would take
+    n_slots * max_pages."""
+    from repro.configs.registry import get_arch
+    from repro.models.model import build_model
+    from repro.serving import Engine, Request
+
+    cfg = get_arch("llama3.2-1b", reduced=True)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = Engine(model, params, n_slots=3, max_len=16, decode_block=4, page_size=4)
+    for n in (6, 9):
+        eng.submit(Request(prompt=np.arange(n, dtype=np.int32), max_new_tokens=5))
+    eng.step()  # prefill both, then one fused block of 4 decode steps
+    assert eng.host_syncs == 1 and eng.max_pages == 4
+    # positions at launch 6, 9 and an empty slot at 0: pages 2 + 3 + 1
+    assert eng.attn_pages_walked == 4 * (2 + 3 + 1)
+    assert eng.attn_pages_offered == 4 * 3 * 4
+    eng.reset_counters()
+    assert eng.attn_pages_walked == eng.attn_pages_offered == 0

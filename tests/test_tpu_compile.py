@@ -71,7 +71,8 @@ def test_flat_decode_compiles(one_chip, S):
     assert _compile_has_kernel(decode_attention_pallas, *args)
 
 
-@pytest.mark.parametrize("n_tbl", [1, 9])  # one page, and the smoke's 1152-token table
+# one page, the smoke's 1152-token table, the chat benchmark's 2560-token one
+@pytest.mark.parametrize("n_tbl", [1, 9, 20])
 def test_paged_decode_compiles(one_chip, n_tbl):
     P = B * n_tbl + 1
     args = (
