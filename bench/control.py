@@ -1,7 +1,8 @@
 """The control of a cell's correctness check, run on the chip at the cell's
 own size: for each seed, one short run of the cell whose check is made
-twice, on the program's output and on the plain reference computed a
-precision down (float8 e4m3 operands, see ``bench/reference``).  The control
+twice, on the program's output and on the plain reference the
+configuration names (``bench/reference/<name>.py``, the lookup the check
+itself makes) computed a precision down (float8 e4m3 operands).  The control
 has to read above the cell's limit, and the program below it.
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3 --seconds 10

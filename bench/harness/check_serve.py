@@ -1,5 +1,6 @@
 """Whether the served tokens are right: each sampled request's prompt and
-served tokens go once through the plain reference, and the number compared
+served tokens go once through the plain reference the configuration names
+(``bench/reference/<config["reference"]>.py``), and the number compared
 is the widest gap by which a served token's reference logit lies below the
 reference's best at that position.  Greedy decoding makes the gap 0 up to
 rounding; a wrong token, cache entry or kernel opens it."""
@@ -10,7 +11,7 @@ from typing import List
 
 import numpy as np
 
-from bench.harness import seeds
+from bench.harness import seeds, spec
 
 
 def sample(records, seed: int, n: int) -> List[object]:
@@ -29,19 +30,18 @@ def gaps(params, config: dict, recs, *, control: bool = False):
     those the float8 reference would put first at each position."""
     import jax.numpy as jnp
 
-    from bench.reference import decoder
-
+    reference = spec.load_reference(config["reference"])
     worst, n = 0.0, 0
     for rec in recs:
         prompt = np.asarray(rec.req.prompt, np.int32)
         toks = np.asarray(rec.req.tokens, np.int32)
         seq = np.concatenate([prompt, toks[:-1]])
         rows = np.arange(prompt.size - 1, seq.size)
-        x = decoder.hidden(params, seq, config)
-        ref = decoder.logits_rows(params, x, rows, config)
+        x = reference.hidden(params, seq, config)
+        ref = reference.logits_rows(params, x, rows, config)
         if control:
-            xc = decoder.hidden(params, seq, config, fp8=True)
-            low = decoder.logits_rows(params, xc, rows, config, fp8=True)
+            xc = reference.hidden(params, seq, config, fp8=True)
+            low = reference.logits_rows(params, xc, rows, config, fp8=True)
         else:
             low = None
         for r, lg in ref:

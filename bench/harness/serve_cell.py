@@ -37,7 +37,7 @@ def run(run, args, t_process, compiles, tracer):
           flush=True)
     load = gen.open_loop(mix, float(cell["rate_rps"]), run.seconds, cfg.vocab, args.seed)
     leaves, _ = weights.served_shapes(model, comp)
-    run.cost = per_token(leaves, config)
+    run.cost = per_token(leaves, config, route=(cfg.top_k, cfg.n_experts))
     run.n_slots = server.n_slots
     run.kv_pages = server.kv_pages
     run.decode_block = int(config["engine"]["decode_block"])

@@ -2,20 +2,25 @@
 its traffic mix and the metrics ``BENCHMARK.json`` gives it.
 
 Every piece lives in a file of its own under ``bench/``, so a new cell, mix,
-configuration or per-layer metric is a new file and a new entry in
-``BENCHMARK.json``, never an edit of this code.
+configuration, plain reference or per-layer metric is a new file and a new
+entry in ``BENCHMARK.json``, never an edit of this code.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
+import re
+import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+REFERENCES = BENCH / "reference"
 
 
 @dataclasses.dataclass
@@ -43,13 +48,31 @@ def _json(path: Path) -> dict:
         return json.load(f)
 
 
+@functools.lru_cache(maxsize=None)
+def load_module(path: Path) -> ModuleType:
+    """The Python file at ``path``, loaded once per process."""
+    name = "bench_file" + re.sub(r"\W", "_", str(path))
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod  # so that a dataclass in the file finds its module
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str) -> Callable:
     """``bench/metrics/<name>.py`` defines ``read(run)``."""
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(BENCH / "metrics" / f"{name}.py").read
+
+
+def load_reference(name: str) -> ModuleType:
+    """``bench/reference/<name>.py``, the plain reference a configuration
+    names under ``"reference"``.  It defines ``hidden(params, tokens, config,
+    *, fp8)`` and ``logits_rows(params, x, rows, config, *, fp8)`` as
+    ``decoder.py`` does."""
+    path = REFERENCES / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"bench: reference {name!r} has no file {path}")
+    return load_module(path)
 
 
 def _applies(entry: dict, cell: str) -> bool:

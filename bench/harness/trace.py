@@ -8,6 +8,13 @@ operand shapes included; a loop's event encloses its body's).  Device busy
 time is the union of the op intervals inside the traced window, which the
 harness marks with a ``bench.window`` host span; its ``bench.step`` spans
 around each engine step name the idle gaps.
+
+A kernel is found by name: ``bench/cost/kernels/<kernel>.py`` gives the
+``PATTERN`` its trace events match and ``least(run, calls, peaks)``, the
+least time its calls in the window need (None where it cannot be known).
+A new kernel's roofline is that file and a per-layer metric,
+``bench/metrics/roofline_pct.<name>.py``, that reads
+``kernel_roofline(run, "<kernel>")``.
 """
 
 from __future__ import annotations
@@ -19,13 +26,14 @@ import re
 import shutil
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
-KERNELS = {
-    "lowrank": re.compile(r"^%lowrank_matmul(_batched)?_pallas[.\s]"),
-    "paged_decode": re.compile(r"^%paged_decode_attention_pallas[.\s]"),
-}
-SHAPE = re.compile(r"\b(bf16|f32|f16|s32|s8|u8|u32|pred)\[([0-9,]*)\]")
+from bench.cost.hlo import SHAPE
+from bench.cost.hlo import shapes as _shapes  # noqa: F401  (the tests read it here)
+from bench.harness import spec
+
+KERNEL_COSTS = spec.BENCH / "cost" / "kernels"
 DTYPE = {"bfloat16": "bf16", "float32": "f32", "float16": "f16", "int32": "s32", "int8": "s8"}
 
 
@@ -48,6 +56,11 @@ class Trace:
     counters: Dict[str, int]
     steps: list
     breakdown: dict
+
+
+def kernels() -> Dict[str, ModuleType]:
+    """Every kernel with a cost file, by name, in the order of the names."""
+    return {p.stem: spec.load_module(p) for p in sorted(KERNEL_COSTS.glob("*.py"))}
 
 
 def shape_text(dtype, shape) -> str:
@@ -193,7 +206,8 @@ def reduce_file(path: str, counters: dict, steps: list, *, chips: int = 1,
     lo, hi = win[0] if win else (min(o.start_ns for l in ops.values() for o in l),
                                  max(o.start_ns + o.dur_ns for l in ops.values() for o in l))
     busy, program, gaps_all = [], {}, []
-    kernels: Dict[str, List[Op]] = {k: [] for k in KERNELS}
+    patterns = {k: mod.PATTERN for k, mod in kernels().items()}
+    found: Dict[str, List[Op]] = {k: [] for k in patterns}
     tally: Dict[str, float] = {}
     for chip, lst in ops.items():
         lst = [o for o in lst if lo <= o.start_ns and o.start_ns + o.dur_ns <= hi]
@@ -205,11 +219,11 @@ def reduce_file(path: str, counters: dict, steps: list, *, chips: int = 1,
         by_module: Dict[tuple, List[Op]] = {}
         for o in lst:
             tally[_label(o)] = tally.get(_label(o), 0.0) + o.self_ns
-            for k, pat in KERNELS.items():
+            for k, pat in patterns.items():
                 if pat.search(o.text):
-                    kernels[k].append(o)
+                    found[k].append(o)
             by_module.setdefault(o.module, []).append(o)
-        _feeds([o for k in kernels.values() for o in k], lst)
+        _feeds([o for k in found.values() for o in k], lst)
         kinds = {}
         for m in by_module:
             kinds[m] = classify(m, [o.text for o in by_module[m]], list(pool_shapes))
@@ -233,7 +247,7 @@ def reduce_file(path: str, counters: dict, steps: list, *, chips: int = 1,
     }
     return Trace(window_s=(hi - lo) / 1e9, busy_s=sum(busy) / n / 1e9,
                  program_s={k: v / n / 1e9 for k, v in program.items()},
-                 kernels=kernels, counters=counters,
+                 kernels=found, counters=counters,
                  steps=steps, breakdown=breakdown)
 
 
@@ -287,42 +301,16 @@ class Tracer:
         return out
 
 
-def _shapes(text: str):
-    return [tuple(int(x) for x in dims.split(",") if x) for _dt, dims in SHAPE.findall(text)]
-
-
 def kernel_roofline(run, kind: str) -> Optional[float]:
     """Least time of the kernel's calls in the traced window over their
-    device time, in percent; None where no call of it ran there."""
-    from bench.cost.attention import paged_decode
-    from bench.cost.lowrank import lowrank_call
-
+    device time, the ops that fed their operands included, in percent; None
+    where no call of it ran there or its least time is not known."""
     t = run.trace
     if t is None or not t.kernels.get(kind):
         return None
     calls = t.kernels[kind]
     dev_s = sum(o.dur_ns + o.feed_ns for o in calls) / 1e9
-    pk = run.peaks
-    if kind == "lowrank":
-        least = 0.0
-        for o in calls:
-            # the custom call's output shape, then its operands x, A, B
-            x, _a, b = _shapes(o.text)[1:4]
-            L = x[0] if len(x) == 3 else 1
-            M, K = x[-2:]
-            r, N = b[-2:]
-            flops, nbytes = lowrank_call(M, K, r, N, L=L)
-            least += max(flops / pk["bf16_flops_per_s"], nbytes / pk["hbm_bytes_per_s"])
-        return 100.0 * least / dev_s
-    if kind == "paged_decode":
-        c = run.config
-        ctx = sum(s.decode_ctx for s in t.steps)
-        n = sum(s.decode_tokens for s in t.steps)
-        if not n:
-            return None
-        flops, nbytes = paged_decode(ctx, n, heads=c["num_attention_heads"],
-                                     kv_heads=c["num_key_value_heads"],
-                                     head_dim=c["head_dim"], layers=c["num_hidden_layers"])
-        least = max(flops / pk["bf16_flops_per_s"], nbytes / pk["hbm_bytes_per_s"])
-        return 100.0 * least / dev_s
-    raise ValueError(kind)
+    least = kernels()[kind].least(run, calls, run.peaks)
+    if least is None:
+        return None
+    return 100.0 * least / dev_s

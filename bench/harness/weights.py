@@ -17,8 +17,9 @@ from typing import Optional
 
 from repro.configs.registry import get_arch
 
-# configuration-file key -> ArchConfig field, checked against the registry
-_DENSE_KEYS = {
+# configuration-file key -> ArchConfig field, by family; each key the file
+# holds is checked against the registry
+_KEYS = {
     "hidden_size": "d_model",
     "num_attention_heads": "n_heads",
     "num_key_value_heads": "n_kv_heads",
@@ -26,23 +27,66 @@ _DENSE_KEYS = {
     "vocab_size": "vocab",
     "rope_theta": "rope_theta",
     "norm_eps": "norm_eps",
+    "rms_norm_eps": "norm_eps",
     "tie_word_embeddings": "tie_embeddings",
+    # multi-head latent attention
+    "q_lora_rank": "q_lora_rank",
+    "kv_lora_rank": "kv_lora_rank",
+    "qk_nope_head_dim": "qk_nope_dim",
+    "qk_rope_head_dim": "qk_rope_dim",
+    "v_head_dim": "v_head_dim",
 }
-_FFN_KEYS = {"dense": {"intermediate_size": "d_ff"}}
+_FAMILY_KEYS = {
+    "dense": {"intermediate_size": "d_ff"},
+    "moe": {
+        "intermediate_size": "dense_d_ff",
+        "n_routed_experts": "n_experts",
+        "num_experts_per_tok": "top_k",
+        "moe_intermediate_size": "moe_d_ff",
+        "n_shared_experts": "n_shared_experts",
+        "first_k_dense_replace": "first_dense_layers",
+    },
+}
 
 
 def arch_config(config: dict):
-    """The registry's ``ArchConfig`` cut to the file's depth; every other size
-    in the file must be what the registry builds, or the run stops."""
+    """The registry's ``ArchConfig`` as the file states it.
+
+    The depth, ``num_hidden_layers``, is always the file's.  Every other key
+    the file holds that the tables above name must be what the registry
+    builds.  The file's own ``arch_fields`` (key -> ``ArchConfig`` field)
+    add to the tables and take precedence; a key named there is applied as
+    a cut where ``reduced`` lists it, and is checked otherwise.  No other
+    key is cut: a table key in ``reduced`` stops the run, so that no width
+    and no router changes unseen.  A key the file lacks is not demanded.
+    An unknown family, or an ``arch_fields`` field ``ArchConfig`` lacks,
+    stops the run.
+    """
     base = get_arch(config["arch"])
-    cfg = dataclasses.replace(base, n_layers=int(config["num_hidden_layers"]))
-    keys = dict(_DENSE_KEYS, **_FFN_KEYS[cfg.family])
+    if base.family not in _FAMILY_KEYS:
+        raise SystemExit(f"bench: {config['name']}: the registry's {config['arch']} is of "
+                         f"family {base.family!r}; the benchmark knows {sorted(_FAMILY_KEYS)}")
+    own = config.get("arch_fields", {})
+    keys = {**_KEYS, **_FAMILY_KEYS[base.family], **own}
+    fields = {f.name for f in dataclasses.fields(base)}
+    cuts = {"n_layers": int(config["num_hidden_layers"])}
     for key, field in keys.items():
-        if getattr(cfg, field) != config[key]:
+        if field not in fields:
+            raise SystemExit(f"bench: {config['name']}: {key} sets {field!r}, "
+                             f"which ArchConfig does not have")
+        if key not in config:
+            continue
+        if key in config.get("reduced", ()):
+            if key not in own:
+                raise SystemExit(f"bench: {config['name']}: reduced lists {key}, which sets "
+                                 f"{field}; only the depth and keys named in arch_fields are cut")
+            cuts[field] = config[key]
+        elif getattr(base, field) != config[key]:
             raise SystemExit(
                 f"bench: {config['name']}: {key}={config[key]!r} but the "
-                f"registry's {config['arch']} has {field}={getattr(cfg, field)!r}"
+                f"registry's {config['arch']} has {field}={getattr(base, field)!r}"
             )
+    cfg = dataclasses.replace(base, **cuts)
     if cfg.dtype != config["torch_dtype"]:
         raise SystemExit(f"bench: {config['name']}: dtype {cfg.dtype} != {config['torch_dtype']}")
     return cfg

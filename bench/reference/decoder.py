@@ -1,14 +1,17 @@
-"""Plain reference of the served decoder: the forward pass in float32
-``jax.numpy`` at the highest matmul precision, one layer at a time, with no
-kernel, cache or batching, written from the configuration file alone.
+"""Plain reference of the served dense decoder (``"reference": "decoder"``):
+the forward pass in float32 ``jax.numpy`` at the highest matmul precision,
+one layer at a time, with no kernel, cache or batching, written from the
+configuration file alone.
 
 It follows the model as the configuration states it, departures included:
 RMSNorm, rotary position embedding over the whole head (rotate-half
-layout), grouped-query attention, gated SiLU MLP, and for a mixture of
-experts a softmax router whose top ``k`` gates are renormalized, with every
-routed token computed (no capacity).  The weights are the tree the
-benchmark made from the seed, read by name; a factored leaf ``{"a", "b"}``
-applies as ``(x @ a) @ b``.
+layout), grouped-query attention and a gated SiLU MLP in every layer of
+``params["layers"]``.  It has no mixture of experts, no latent attention
+and no leading dense layers: a configuration with those names a reference
+of its own, ``bench/reference/<name>.py`` with this module's ``hidden`` and
+``logits_rows``.  The weights are the tree the benchmark made from the
+seed, read by name; a factored leaf ``{"a", "b"}`` applies as
+``(x @ a) @ b``.
 
 ``precision="fp8"`` is the control: every matmul operand is rounded to
 float8 e4m3 with a per-tensor (weights) or per-row (activations) absmax

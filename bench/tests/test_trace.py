@@ -7,9 +7,11 @@ import shutil
 import types
 from pathlib import Path
 
+from unittest import mock
+
 import pytest
 
-from bench.harness import trace
+from bench.harness import spec, trace
 from bench.tests.tiny import PEAKS
 
 DATA = Path(__file__).resolve().parent / "data"
@@ -72,3 +74,50 @@ def test_feed_op_charged_once():
     k2 = trace.Op(call.format(i=2), 20, 2)
     trace._feeds([k1, k2], [feed, k1, k2])
     assert (k1.feed_ns, k2.feed_ns) == (5, 0)
+
+
+def _pinned_run(tmp_path):
+    """The recorded trace with a fixed record of 16 traced engine steps, the
+    minitron4b-rsi configuration cut to the trace's 2 layers."""
+    from bench.cost.model import per_token
+    from bench.harness import weights
+    from bench.harness.serve_loop import Step
+    from repro.models.model import build_model
+
+    facts = json.loads((DATA / "serve.json").read_text())
+    steps = [Step(t0=0.25 * i, t1=0.25 * i + 0.125 + 0.001 * i, emitted=3,
+                  decode_ctx=300 + 37 * i, decode_tokens=3 if i % 4 else 0,
+                  prefill_done=[256] if i % 4 == 0 else [], traced=True) for i in range(16)]
+    config = dict(json.loads((spec.BENCH / "configs" / "minitron4b-rsi.json").read_text()),
+                  num_hidden_layers=facts["layers"])
+    src = DATA / "serve.xplane.pb.gz"
+    dst = tmp_path / "serve.xplane.pb"
+    with gzip.open(src, "rb") as f, open(dst, "wb") as g:
+        shutil.copyfileobj(f, g)
+    t = trace.reduce_file(str(dst), facts["counters"], steps, pool_shapes=facts["pool_shapes"])
+    leaves, _ = weights.served_shapes(build_model(weights.arch_config(config)),
+                                      config["compression"])
+    return types.SimpleNamespace(trace=t, peaks=spec.peaks_for("TPU v5 lite"), config=config,
+                                 steps=steps, cost=per_token(leaves, config))
+
+
+def test_rooflines_and_mfu_read_what_they_read_before(tmp_path):
+    """The kernels found by name and the model cost give, bit for bit, what
+    the harness gave with the two kernels written into it (commit a503d8b)."""
+    run = _pinned_run(tmp_path)
+    assert run.cost == (191389696, 477728768, 24576)
+    assert (len(run.trace.kernels["lowrank"]), len(run.trace.kernels["paged_decode"])) == (200, 32)
+    assert spec.load_reader("roofline_pct.lowrank_matmul")(run) == 71.64661323573532
+    assert spec.load_reader("roofline_pct.paged_decode_attention")(run) == 7.231547762644412
+    assert spec.load_reader("mfu.serve")(run) == 0.05398025071927978
+
+
+def test_a_kernel_is_found_by_its_cost_file(tmp_path):
+    """A kernel's pattern and least time come from ``<kernel>.py`` in the
+    kernel cost directory: a stand-in there is found, and no other."""
+    with mock.patch.object(trace, "KERNEL_COSTS", DATA / "kernels"):
+        run = _pinned_run(tmp_path)
+        calls = run.trace.kernels["stub_decode"]
+        assert set(run.trace.kernels) == {"stub_decode"} and len(calls) == 32
+        dev_s = sum(o.dur_ns + o.feed_ns for o in calls) / 1e9
+        assert trace.kernel_roofline(run, "stub_decode") == 100.0 * 32e-6 / dev_s
